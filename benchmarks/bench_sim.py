@@ -333,11 +333,22 @@ def run_mesh_bench(n: int = 24, R: int = 8, reps: int = 3, seed: int = 0,
             "phases": phases}
 
 
+def _refuse_cpu_children_on_tpu(mode: str) -> None:
+    """The forced-host-device children time XLA:CPU; on a chip host their
+    rows would pass CPU numbers off under device names."""
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            f"bench_sim --mode {mode} times forced CPU devices in a child "
+            "process and reports no chip numbers; on a TPU host run "
+            "chip_smoke.py --four-chips for the mesh paths instead")
+
+
 def run_mesh_bench_subprocess(n: int = 24, R: int = 8, reps: int = 3,
                               seed: int = 0, mesh_shape: str = "8") -> dict:
     """Re-execute this file with forced host devices (XLA_FLAGS must be set
     BEFORE jax initializes its backend, which importing this module already
     did in the calling process) and collect the mesh-bench JSON."""
+    _refuse_cpu_children_on_tpu("mesh|mesh2d")
     from repro.launch.mesh import parse_sim_mesh_shape
     n_dev = int(np.prod(parse_sim_mesh_shape(mesh_shape)))
     fd, out = tempfile.mkstemp(suffix=".json")
@@ -429,6 +440,7 @@ def run_tp_bench_subprocess(n: int = 8, R: int = 8, reps: int = 3,
                             seed: int = 0, mesh_shape: str = "2x4") -> dict:
     """Re-execute this file with forced host devices and collect the
     tp-bench JSON (same contract as ``run_mesh_bench_subprocess``)."""
+    _refuse_cpu_children_on_tpu("tp")
     from repro.launch.mesh import parse_sim_mesh_shape
     n_dev = int(np.prod(parse_sim_mesh_shape(mesh_shape)))
     fd, out = tempfile.mkstemp(suffix=".json")

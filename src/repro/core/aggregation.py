@@ -21,11 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                    # jax >= 0.5 top-level export
-    _shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4.x experimental location
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def aggregate(params_stack, weights):
     """params_stack: pytree with leading client dim C; weights: (C,) summing to 1."""
@@ -68,7 +63,7 @@ def aggregate_sharded(mesh, params_stack, weights, axis: str = "data"):
         return jax.tree.map(lambda x: jax.lax.psum(x, axis), local)
 
     specs_in = jax.tree.map(lambda _: P(axis), params_stack)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_agg, mesh=mesh,
         in_specs=(specs_in, P(axis)),
         out_specs=jax.tree.map(lambda _: P(), params_stack))
@@ -92,8 +87,7 @@ def fedavg_delta(global_params, params_stack, weights):
 # cluster parameters as one contiguous (C, D_pad) fp32 buffer (core/plane.py)
 # so aggregation is a single contraction with no per-call tree_flatten /
 # concatenate / pad.  On TPU the contraction routes through the Pallas
-# ``kernels/fedagg`` kernel (the plane length is already block-aligned);
-# elsewhere it lowers to one dot.
+# ``kernels/fedagg`` kernel; elsewhere it lowers to one dot.
 
 
 def _use_fedagg_kernel() -> bool:
@@ -107,7 +101,7 @@ def aggregate_plane(plane, weights, *, use_kernel: bool | None = None):
         use_kernel = _use_fedagg_kernel()
     if use_kernel:
         from repro.kernels.fedagg.ops import aggregate_plane as _kernel_plane
-        return _kernel_plane(plane, w, interpret=False)
+        return _kernel_plane(plane, w)
     return jnp.tensordot(w, plane, axes=(0, 0))
 
 
@@ -174,9 +168,9 @@ def aggregate_plane_sharded(mesh, plane, weights, *, axis: str = "data",
         return jax.lax.psum(
             aggregate_plane(p, wl, use_kernel=use_kernel), axis)
 
-    fn = _shard_map(local_agg, mesh=mesh,
-                    in_specs=(P(axis, model_axis), P(axis)),
-                    out_specs=P(model_axis))
+    fn = jax.shard_map(local_agg, mesh=mesh,
+                       in_specs=(P(axis, model_axis), P(axis)),
+                       out_specs=P(model_axis))
     out = fn(plane, w)
     return out[:D] if pad_d else out
 

@@ -20,9 +20,8 @@ import numpy as np
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# Multiple every plane length is padded to: keeps the Pallas fedagg block
-# grid divisible without per-call padding, and matches the 128-lane TPU
-# register tile.
+# Multiple every plane length is padded to: the 128-lane TPU register tile,
+# so every per-device column slice starts on a lane boundary.
 PLANE_ALIGN = 128
 
 
@@ -50,8 +49,7 @@ class PlaneSpec:
 def make_plane_spec(params_template, *, model_size: int = 1) -> PlaneSpec:
     """``model_size`` > 1 column-shards the plane over a mesh ``model``
     axis: D is padded to a multiple of ``model_size × PLANE_ALIGN`` so every
-    device's column slice is itself PLANE_ALIGN-aligned and the Pallas
-    ``fedagg`` tile grid stays divisible per device."""
+    device's column slice is itself PLANE_ALIGN-aligned."""
     flat, unravel = ravel_pytree(params_template)
     d = flat.shape[0]
     align = PLANE_ALIGN * max(1, int(model_size))
@@ -80,8 +78,7 @@ class TPPlaneSpec:
 
     All plane algebra stays valid: aggregation/delta/bank merges are linear
     and act identically on every duplicated copy, and ``d_loc`` is padded to
-    a PLANE_ALIGN multiple so ``d_pad = msize·d_loc`` keeps the fedagg tile
-    grid divisible per device.
+    a PLANE_ALIGN multiple so every device's chunk is lane-aligned.
     """
     d: int                  # true (unduplicated) parameter count
     d_pad: int              # plane length = msize · d_loc

@@ -397,8 +397,8 @@ class FedRAC:
     def plane_spec(self, level: int):
         """Flat-parameter-plane recipe for one level (cached; the template
         init is shape-only).  On a 2D mesh D pads to a multiple of
-        ``model_size × PLANE_ALIGN`` so each device's column slice keeps the
-        Pallas fedagg tile grid aligned."""
+        ``model_size × PLANE_ALIGN`` so each device's column slice stays
+        lane-aligned."""
         if level not in self._plane_specs:
             template = self.family.init(jax.random.PRNGKey(0), level)
             if self._tp:
@@ -644,10 +644,6 @@ class FedRAC:
         out = {}
         for key, prog in self._programs.items():
             progs = prog if isinstance(prog, tuple) else (prog,)
-            if not all(hasattr(p, "_cache_size") for p in progs):
-                raise RuntimeError(
-                    "this jax build has no jit _cache_size; compile "
-                    "telemetry unavailable (do not silently report 0)")
             out[key] = sum(p._cache_size() for p in progs)
         return out
 
@@ -826,6 +822,13 @@ class FedRAC:
             p_stack = jax.tree.map(
                 lambda x: jnp.broadcast_to(x[None], (C_loc,) + x.shape),
                 params)
+            if axis is not None:
+                # the member copies start replicated but train on this
+                # device's own rows, so the member scan's carry varies
+                # over the data axis from its first step on
+                p_stack = jax.tree.map(
+                    lambda x: jax.lax.pcast(x, (axis,), to="varying"),
+                    p_stack)
             if tp:
                 # member rows over `data`, each member's leaves TP-sharded —
                 # the broadcast stays a broadcast; the forward partitions
@@ -974,9 +977,8 @@ class FedRAC:
             else:
                 in_specs = (Pg,) + tail + (t_in,)
                 out_specs = (Pg,) + ys_specs
-            fn = aggregation._shard_map(block_fn, mesh=self.mesh,
-                                        in_specs=in_specs,
-                                        out_specs=out_specs)
+            fn = jax.shard_map(block_fn, mesh=self.mesh,
+                               in_specs=in_specs, out_specs=out_specs)
             prog = jax.jit(fn, donate_argnums=donate)
         else:
             prog = jax.jit(fn, donate_argnums=donate)

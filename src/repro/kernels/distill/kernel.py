@@ -41,7 +41,7 @@ def _kd_kernel(s_ref, t_ref, lbl_ref, o_ref, st, *, T: float, alpha: float,
     sT, tT = s / T, t / T
     v_idx = j * block_v + jax.lax.broadcasted_iota(jnp.int32,
                                                    (block_n, block_v), 1)
-    lbl = lbl_ref[...]                           # (bn,)
+    lbl = lbl_ref[...]                           # (bn, 1)
 
     # --- teacher-temperature statistics (for the KL) -----------------------
     m_t, l_t, A, B = st[0, :], st[1, :], st[2, :], st[3, :]
@@ -67,7 +67,7 @@ def _kd_kernel(s_ref, t_ref, lbl_ref, o_ref, st, *, T: float, alpha: float,
     st[7, :] = l1 * jnp.exp(m1 - m1_new) + jnp.sum(
         jnp.exp(s - m1_new[:, None]), axis=1)
     st[8, :] = st[8, :] + jnp.sum(
-        jnp.where(v_idx == lbl[:, None], s, 0.0), axis=1)
+        jnp.where(v_idx == lbl, s, 0.0), axis=1)
 
     @pl.when(j == n_v - 1)
     def _final():
@@ -76,8 +76,8 @@ def _kd_kernel(s_ref, t_ref, lbl_ref, o_ref, st, *, T: float, alpha: float,
         z_s1 = st[6, :] + jnp.log(st[7, :])
         kl = st[2, :] / st[1, :] - z_t + z_sT - st[3, :] / st[1, :]
         ce = z_s1 - st[8, :]
-        o_ref[...] = (alpha * ce + (1.0 - alpha) * (T ** 2) * kl).astype(
-            o_ref.dtype)
+        loss = alpha * ce + (1.0 - alpha) * (T ** 2) * kl
+        o_ref[...] = loss.reshape(1, block_n).astype(o_ref.dtype)
 
 
 def kd_loss_rows(student, teacher, labels, *, T: float = 2.0,
@@ -96,10 +96,10 @@ def kd_loss_rows(student, teacher, labels, *, T: float = 2.0,
         in_specs=[
             pl.BlockSpec((block_n, block_v), lambda i, j: (i, j)),
             pl.BlockSpec((block_n, block_v), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((9, block_n), jnp.float32)],
         interpret=interpret,
-    )(student, teacher, labels)
+    )(student, teacher, labels.reshape(N, 1))[0]

@@ -1,36 +1,34 @@
 """jit'd pytree wrapper for the fedagg kernel: ravel → kernel → unravel."""
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.fedagg.kernel import weighted_aggregate
+
+# Bytes of one (C, block_d) fp32 input panel: double-buffered, plus the
+# weighted product, it stays well inside the default scoped VMEM.
+PANEL_BYTES = 2 << 20
 
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pick_block(D: int, block_d: int) -> int:
-    """Largest power-of-two block ≤ ``block_d`` that divides D (always
-    terminates: every D divides by 1)."""
-    bd = min(block_d, D)
-    while D % bd:
-        bd //= 2
-    return bd
+def _pick_block(C: int, D: int) -> int:
+    """Widest 128-multiple column block whose (C, block) fp32 panel fits
+    ``PANEL_BYTES``; all of D when that is narrower.  D need not divide by
+    it: the kernel's last block overhangs."""
+    bd = max(128, PANEL_BYTES // (4 * C) // 128 * 128)
+    return D if D <= bd else bd
 
 
-def aggregate_plane(plane, weights, *, block_d: int = 2048,
-                    interpret: bool | None = None):
+def aggregate_plane(plane, weights, *, interpret: bool | None = None):
     """Weighted aggregate straight on a flat parameter plane (C, D) → (D,).
 
-    The plane path of the dispatch pipeline: D is already padded to a
-    multiple of ``core.plane.PLANE_ALIGN`` at spec time, so — unlike
-    ``aggregate_tree`` — there is no per-call flatten/concatenate/pad; the
-    kernel grid tiles D at the largest power-of-two block ≤ ``block_d``
-    that divides it.
+    The plane path of the dispatch pipeline: the plane is already one
+    contiguous buffer, so — unlike ``aggregate_tree`` — there is no per-call
+    flatten/concatenate.
 
     Under ``shard_map`` this is the PER-DEVICE inner loop of the sharded
     plane aggregation (``aggregation.aggregate_plane_sharded`` and the
@@ -38,36 +36,25 @@ def aggregate_plane(plane, weights, *, block_d: int = 2048,
     count — the zero-weight padding rows that make C divisible by the mesh
     axis contract to nothing — and one psum over ``data`` outside completes
     the all-reduce.  On a 2D (data × model) mesh D is the device's LOCAL
-    column slice (``core.plane.make_plane_spec(model_size=…)`` pads the
-    global plane to a multiple of ``model_size × PLANE_ALIGN`` precisely so
-    this per-device grid stays block-divisible); column slices never need
-    reducing, so no collective is added."""
+    column slice; column slices never need reducing, so no collective is
+    added."""
     interpret = _interpret_default() if interpret is None else interpret
-    bd = _pick_block(plane.shape[1], block_d)
+    C, D = plane.shape
     return weighted_aggregate(plane.astype(jnp.float32),
-                              weights.astype(jnp.float32), block_d=bd,
-                              interpret=interpret)
+                              weights.astype(jnp.float32),
+                              block_d=_pick_block(C, D), interpret=interpret)
 
 
-def aggregate_tree(params_stack, weights, *, block_d: int = 2048,
-                   interpret: bool | None = None):
+def aggregate_tree(params_stack, weights, *, interpret: bool | None = None):
     """params_stack: pytree with leading client axis C → aggregated pytree."""
-    interpret = _interpret_default() if interpret is None else interpret
     leaves, treedef = jax.tree_util.tree_flatten(params_stack)
     C = leaves[0].shape[0]
-    flats = [l.reshape(C, -1) for l in leaves]
-    sizes = [f.shape[1] for f in flats]
-    cat = jnp.concatenate(flats, axis=1).astype(jnp.float32)
-    D = cat.shape[1]
-    bd = min(block_d, D)
-    pad = (-D) % bd
-    if pad:
-        cat = jnp.pad(cat, ((0, 0), (0, pad)))
-    out = weighted_aggregate(cat, weights.astype(jnp.float32), block_d=bd,
-                             interpret=interpret)[:D]
+    cat = jnp.concatenate([l.reshape(C, -1) for l in leaves], axis=1)
+    out = aggregate_plane(cat, weights, interpret=interpret)
     parts = []
     pos = 0
-    for leaf, sz in zip(leaves, sizes):
+    for leaf in leaves:
+        sz = leaf[0].size
         parts.append(out[pos:pos + sz].reshape(leaf.shape[1:]).astype(leaf.dtype))
         pos += sz
     return jax.tree_util.tree_unflatten(treedef, parts)

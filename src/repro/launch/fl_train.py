@@ -22,6 +22,7 @@ from repro.core.resources import (LAMBDA_EQUAL, LAMBDA_PAPER, TABLE_III,
                                   participants_from_matrix)
 from repro.data.partition import dirichlet_partition
 from repro.data.synthetic import SPECS, make_classification, train_test_split
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run(args):
@@ -89,6 +90,7 @@ def main(argv=None):
     ap.add_argument("--no-kd", action="store_true")
     ap.add_argument("--seed", type=int, default=3)
     args = ap.parse_args(argv)
+    use_compile_cache()
     return run(args)
 
 

@@ -5,12 +5,20 @@ tests and benchmarks must keep seeing 1 device)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """Mesh whose axes are all ``Auto``: the sharding rules here are GSPMD
+    constraints and ``shard_map`` specs, not sharding-in-types, so the
+    ``Explicit`` axes ``jax.make_mesh`` defaults to would refuse them."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:
@@ -20,7 +28,7 @@ def data_axes(mesh) -> tuple:
 
 def make_host_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh over host devices (tests use 8 forced host devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def parse_sim_mesh_shape(shape) -> tuple:

@@ -30,6 +30,7 @@ from repro.ckpt.manifest import CheckpointManager
 from repro.configs import get_config, list_archs
 from repro.core.plane import make_plane_spec
 from repro.core.scaling import compress_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import registry, transformer
 from repro.obs import NULL_OBS, make_observability
 
@@ -167,6 +168,7 @@ def main(argv=None):
     ap.add_argument("--watch-poll-s", type=float, default=0.0, metavar="S",
                     help="sleep between watched batches (poll interval)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     obs = (make_observability(trace=False)
            if args.metrics_text or args.metrics_json else NULL_OBS)

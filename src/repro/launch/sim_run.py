@@ -47,9 +47,10 @@ from repro.core import server as srv
 from repro.core.families import cnn_family
 from repro.core.resources import (LAMBDA_EQUAL, LAMBDA_PAPER, Fleet,
                                   participants_from_matrix)
-from repro.launch.mesh import make_sim_mesh
 from repro.data.partition import dirichlet_partition
 from repro.data.synthetic import SPECS, make_classification, train_test_split
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_sim_mesh
 from repro.obs import make_observability
 from repro.sim import (SCENARIOS, FleetSim, FleetSimConfig, HeterogeneitySim,
                        SimConfig, make_fleet_trace, make_trace,
@@ -227,6 +228,18 @@ def run_fleet(args):
     return report
 
 
+def make_sim(args, eng, *, obs=None, checkpoint=None, faults=None):
+    """The heterogeneity simulator over ``eng`` for the parsed ``args``."""
+    trace = make_trace(args.trace, args.participants, args.rounds,
+                       seed=args.seed, **_trace_knobs(args))
+    return HeterogeneitySim(eng, trace, SimConfig(
+        rounds=args.rounds, mar_policy=args.mar_policy,
+        schedule=args.schedule, eval_every=args.eval_every,
+        select=args.select, select_budget=args.select_budget,
+        mode=args.mode, max_staleness=args.max_staleness), obs=obs,
+        checkpoint=checkpoint, faults=faults)
+
+
 def run(args):
     if args.fleet_size:
         return run_fleet(args)
@@ -242,30 +255,20 @@ def run(args):
                    ", replicated member forward" if eng._mesh_m > 1 else "")
         print(f"mesh={dict(eng.mesh.shape)} "
               f"(member axis sharded {eng._mesh_n}-way{plane_txt}{fwd_txt})")
-    trace = make_trace(args.trace, args.participants, args.rounds,
-                       seed=args.seed, **_trace_knobs(args))
     obs = None
     if args.metrics_out or args.trace_out or args.fence:
         obs = make_observability(fence=args.fence)
-    sim = HeterogeneitySim(eng, trace, SimConfig(
-        rounds=args.rounds, mar_policy=args.mar_policy,
-        schedule=args.schedule, eval_every=args.eval_every,
-        select=args.select, select_budget=args.select_budget,
-        mode=args.mode, max_staleness=args.max_staleness), obs=obs,
-        checkpoint=ckpt, faults=faults)
+    sim = make_sim(args, eng, obs=obs, checkpoint=ckpt, faults=faults)
     with _graceful_signals():
         try:
             report = sim.run(testb)
         except GracefulShutdown as e:
             _graceful_exit(args, sim, obs, e.signum)
     print(report.timeline())
-    try:
-        stats = eng.compile_stats()
-        print(f"# round programs={len(stats)} "
-              f"xla_compiles={sum(stats.values())} "
-              f"(padding {'on' if eng.cfg.pad_clusters else 'off'})")
-    except RuntimeError:
-        print("# compile telemetry unavailable on this jax build")
+    stats = eng.compile_stats()
+    print(f"# round programs={len(stats)} "
+          f"xla_compiles={sum(stats.values())} "
+          f"(padding {'on' if eng.cfg.pad_clusters else 'off'})")
     _flush_obs(args, obs)
     if args.report_out:
         doc = report.to_dict()
@@ -278,7 +281,7 @@ def run(args):
     return report
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--trace", default="dropout", choices=sorted(SCENARIOS))
     ap.add_argument("--mar-policy", default="drop",
@@ -401,8 +404,12 @@ def main(argv=None):
     ap.add_argument("--corrupt-ckpt", default=None, choices=CORRUPTION_MODES,
                     help="damage the newest checkpoint under --ckpt-dir "
                          "before anything else runs (degradation testing)")
-    args = ap.parse_args(argv)
-    return run(args)
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    use_compile_cache()
+    return run(parse_args(argv))
 
 
 if __name__ == "__main__":

@@ -12,18 +12,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import (apply_mrope, apply_rope, dense_init,
                                  rms_head_norm, softcap)
 from repro.models.tp import shard_hint, tp_ctx
-
-from jax.sharding import PartitionSpec as P
-
-try:                                    # jax >= 0.5 top-level export
-    _shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4.x experimental location
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 NEG_INF = -2.0 ** 30  # large-but-finite: keeps fully-masked rows NaN-free
 
@@ -157,9 +151,10 @@ def attn_forward(p, cfg: ModelConfig, x, positions, *, local: bool = False,
             # the sharded attention path is served by the same kernel
             mesh, axis = c
             hs = P(None, None, axis, None)
-            out = _shard_map(_flash, mesh=mesh,
-                             in_specs=(hs, hs, hs), out_specs=hs,
-                             check_rep=False)(q, k, v)
+            # check_vma off: the Pallas interpreter cannot evaluate a
+            # kernel body whose refs vary over a mesh axis
+            out = jax.shard_map(_flash, mesh=mesh, in_specs=(hs, hs, hs),
+                                out_specs=hs, check_vma=False)(q, k, v)
         else:
             out = _flash(q, k, v)
     elif cfg.attn_impl == "blocked":

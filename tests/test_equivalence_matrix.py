@@ -536,8 +536,8 @@ def test_sampler_draws_independent_of_model_axis():
         # rep check would refuse to stitch draws that varied by model column
         return jax.lax.pmean(idx.astype(jnp.float32), "model")
 
-    fn = aggregation._shard_map(draw, mesh=mesh, in_specs=(P("data"),),
-                                out_specs=P("data", None, None))
+    fn = jax.shard_map(draw, mesh=mesh, in_specs=(P("data"),),
+                       out_specs=P("data", None, None))
     sharded = np.asarray(fn(n))
     full = np.asarray(device_sampler.uniform_indices(key, steps, batch, n))
     np.testing.assert_array_equal(sharded, full.astype(np.float32))

@@ -137,3 +137,28 @@ def test_model_attn_impl_pallas_matches_jnp(key):
     lp, _ = transformer.forward(cfg.replace(attn_impl="pallas"), params, toks)
     np.testing.assert_allclose(np.asarray(lj), np.asarray(lp), atol=2e-4,
                                rtol=1e-3)
+
+
+def test_model_attn_impl_pallas_head_sharded_matches_jnp(key):
+    """Under a TP context the kernel runs per device on its head shard
+    through ``jax.shard_map``; on a 1x1 mesh that path must match the jnp
+    attention."""
+    from repro.configs import get_config
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import transformer
+    from repro.models.tp import tp_shard_ctx
+    cfg = get_config("qwen3-8b", smoke=True).replace(vocab_size=256)
+    params = transformer.init_params(cfg, key)
+    toks = jax.random.randint(key, (2, 64), 0, cfg.vocab_size)
+    mesh = make_host_mesh(1, 1)
+
+    @jax.jit
+    def sharded(params, toks):
+        with tp_shard_ctx(mesh, "model"):
+            return transformer.forward(cfg.replace(attn_impl="pallas"),
+                                       params, toks)[0]
+
+    lj, _ = transformer.forward(cfg, params, toks)
+    np.testing.assert_allclose(np.asarray(lj),
+                               np.asarray(sharded(params, toks)),
+                               atol=2e-4, rtol=1e-3)

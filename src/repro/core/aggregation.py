@@ -198,21 +198,14 @@ def merge_buffered_plane_sharded(mesh, partial_plane, bank_plane,
 
 
 # ------------------------------------------------------------ buffered async
-def compress_bank_rows(rows: list, us: list, cap: int, *, obs=None):
+def compress_bank_rows(rows: list, us: list, cap: int):
     """Fit a banked backlog into ``cap`` carry slots: when membership shrank
     below the backlog (event between dispatch blocks), ALL rows compress
     into ONE weighted-average row.  Σu and Σu·p are preserved exactly, so
     the round-0 bank merge — which only ever sees the products u·p and the
-    total — is unchanged.  Returns (rows, us) untouched when they fit.
-
-    ``obs``: optional Observability bundle; counted host-side only (this
-    helper is never traced), so the counters see one increment per real
-    compression, not per retrace."""
+    total — is unchanged.  Returns (rows, us) untouched when they fit."""
     if len(rows) <= cap:
         return rows, us
-    if obs is not None and obs.on:
-        obs.registry.counter("agg/bank_compressions").inc()
-        obs.registry.counter("agg/bank_rows_compressed").inc(len(rows))
     u = jnp.asarray(us, jnp.float32)
     total = float(u.sum())
     return ([aggregate_plane(jnp.stack(rows), u / total)], [total])
@@ -255,18 +248,14 @@ def anchored_merge_weights(anchor_weight: float, us) -> tuple[float, list[float]
     return float(anchor_weight) / total, [float(u) / total for u in us]
 
 
-def merge_buffered(partial, contribs, norm_weights, *, obs=None):
+def merge_buffered(partial, contribs, norm_weights):
     """Fold banked contributions into a partial FedAvg sum.
 
     ``partial`` is Σ ŵ_i p_i over this round's live members where the ŵ_i
     were normalized by the TOTAL weight (live + buffered), so Σŵ_i < 1;
     adding Σ û_b p_b over the banked params (û_b = norm_weights, also
     normalized by the total) completes a convex combination — one FedAvg
-    over live and stale contributors alike.  ``obs`` (optional
-    Observability bundle) counts merges/rows host-side."""
-    if obs is not None and obs.on and contribs:
-        obs.registry.counter("agg/bank_merges").inc()
-        obs.registry.counter("agg/bank_rows_merged").inc(len(contribs))
+    over live and stale contributors alike."""
     out = partial
     for p, nw in zip(contribs, norm_weights):
         w = float(nw)

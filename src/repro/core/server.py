@@ -532,7 +532,6 @@ class FedRAC:
             pack = self._shard_packs.pop(key)      # LRU: refresh on hit
             self._shard_packs[key] = pack
             return pack
-        t0 = time.perf_counter_ns()
         if self._shard_len_pad is None:
             n_max = max(max((jax.tree.leaves(self._member_shard(q))[0].shape[0]
                              for q in range(len(self.parts))), default=1), 1)
@@ -582,12 +581,8 @@ class FedRAC:
                       else sum(x.nbytes for x in jax.tree.leaves(pack)))
             reg = self.obs.registry
             reg.counter("fl/h2d_bytes").inc(nbytes)
-            reg.counter("fl/pack_builds").inc()
             if delta:
                 reg.counter("fl/pack_delta").inc()
-            self.obs.tracer.complete(
-                "pack_h2d", t0, time.perf_counter_ns() - t0, cat="fl",
-                level=level, bytes=nbytes, delta=delta)
         return pack
 
     def _cluster_programs(self, level: int, use_kd: bool, capacity: int,
@@ -807,16 +802,16 @@ class FedRAC:
         def one_round(g, bank_p, bank_w, r, shards, n_i, tables,
                       counts, step_masks, weights, teacher, offset):
             C_loc = step_masks.shape[0]       # local member rows (mesh-split)
-            key = device_sampler.round_key(seed, r)
-            if balanced:
-                idx = device_sampler.balanced_indices(key, steps, batch,
-                                                      tables, counts,
-                                                      offset=offset)
-            else:
-                idx = device_sampler.uniform_indices(key, steps, batch, n_i,
-                                                     offset=offset)
-            batches = jax.vmap(lambda sh, ix: self._batch_from_gathered(
-                jax.tree.map(lambda a: a[ix], sh)))(shards, idx)
+            with jax.named_scope("sampler"):
+                key = device_sampler.round_key(seed, r)
+                if balanced:
+                    idx = device_sampler.balanced_indices(
+                        key, steps, batch, tables, counts, offset=offset)
+                else:
+                    idx = device_sampler.uniform_indices(
+                        key, steps, batch, n_i, offset=offset)
+                batches = jax.vmap(lambda sh, ix: self._batch_from_gathered(
+                    jax.tree.map(lambda a: a[ix], sh)))(shards, idx)
             params = (spec.to_params(g, mesh=self.mesh) if tp
                       else spec.to_params(_gather_cols(g)))
             p_stack = jax.tree.map(
@@ -838,31 +833,37 @@ class FedRAC:
                     p_stack, spec.leaf_specs())
             teachers = None
             if use_kd:
-                if tp:
-                    t_params = t_spec.to_params(teacher, mesh=self.mesh)
-                elif t_per_round:
-                    t_params = t_spec.to_params(_gather_cols(teacher))
-                else:
-                    t_params = teacher
-                teachers = jax.vmap(
-                    jax.vmap(lambda b: t_loss_fn(t_params, b)[1]))(batches)
-            new_stack, losses = update(p_stack, batches, step_masks, teachers)
-            # keep only this device's column slice of the updated members:
-            # the carry plane, bank rows and aggregate all live column-
-            # sharded, so the full-width member plane is transient
-            stacked = jax.vmap(spec.to_plane)(new_stack)
-            new_plane = (_constrain(stacked, self._pspecs["members"]) if tp
-                         else _local_cols(stacked))
-            total = jnp.sum(weights) + (jnp.sum(bank_w) if banked else 0.0)
-            if axis is not None:
-                total = jax.lax.psum(total, axis)
-            denom = jnp.where(total > 0.0, total, 1.0)
-            local = aggregation.aggregate_plane(new_plane, weights / denom,
-                                                use_kernel=use_kernel)
-            if banked:
-                local = aggregation.merge_buffered_plane(
-                    local, bank_p, bank_w / denom, use_kernel=use_kernel)
-            agg = jax.lax.psum(local, axis) if axis is not None else local
+                with jax.named_scope("teacher_forward"):
+                    if tp:
+                        t_params = t_spec.to_params(teacher, mesh=self.mesh)
+                    elif t_per_round:
+                        t_params = t_spec.to_params(_gather_cols(teacher))
+                    else:
+                        t_params = teacher
+                    teachers = jax.vmap(jax.vmap(
+                        lambda b: t_loss_fn(t_params, b)[1]))(batches)
+            with jax.named_scope("member_step"):
+                new_stack, losses = update(p_stack, batches, step_masks,
+                                           teachers)
+            with jax.named_scope("aggregate"):
+                # keep only this device's column slice of the updated
+                # members: the carry plane, bank rows and aggregate all live
+                # column-sharded, so the full-width member plane is transient
+                stacked = jax.vmap(spec.to_plane)(new_stack)
+                new_plane = (_constrain(stacked, self._pspecs["members"])
+                             if tp else _local_cols(stacked))
+                total = jnp.sum(weights) + (jnp.sum(bank_w) if banked
+                                            else 0.0)
+                if axis is not None:
+                    total = jax.lax.psum(total, axis)
+                denom = jnp.where(total > 0.0, total, 1.0)
+                local = aggregation.aggregate_plane(
+                    new_plane, weights / denom, use_kernel=use_kernel)
+                if banked:
+                    local = aggregation.merge_buffered_plane(
+                        local, bank_p, bank_w / denom, use_kernel=use_kernel)
+                agg = (jax.lax.psum(local, axis) if axis is not None
+                       else local)
             g_next = jnp.where(total > 0.0, agg, g)
             if tp:
                 g_next = _constrain(g_next, self._pspecs["plane"])
@@ -1024,47 +1025,52 @@ class FedRAC:
                 f"teacher_planes carries {teacher_planes.shape[0]} rounds "
                 f"for a {n_rounds}-round block")
         banked = bank is not None
-        pack = self._shard_pack(level, members, cap, balanced)
+        tr = self.obs.tracer
+        with tr.span("shard_pack", cat="fl", level=level, capacity=cap):
+            pack = self._shard_pack(level, members, cap, balanced)
         S = cfg.steps_per_round
         h2d = 0
-        if isinstance(weights, jax.Array) and weights.shape == (cap,):
-            w = weights                   # pre-padded device array: no copy
-        else:
-            if weights is None:
-                weights = [self.assignment.n_eff.get(pid, 1)
-                           for pid in members]
-            w = np.zeros(cap, np.float32)
-            w[:C] = np.asarray(weights, np.float32)
-            h2d += w.nbytes
-            w = self.place_member_sharded(jnp.asarray(w))
-        if isinstance(step_masks, jax.Array) and step_masks.shape == (cap, S):
-            masks = step_masks            # pre-padded device array: no copy
-        else:
-            masks = np.zeros((cap, S), np.float32)
-            masks[:C] = (np.ones((C, S), np.float32) if step_masks is None
-                         else np.asarray(step_masks, np.float32))
-            h2d += masks.nbytes
-            masks = self.place_member_sharded(jnp.asarray(masks))
-        prog = self._dispatch_programs(level, use_kd, cap, n_rounds,
-                                       balanced, banked, want_history,
-                                       t_per_round=t_per_round, pack=pack,
-                                       teacher_example=teacher)
-        if t_per_round:
-            t_arg = teacher_planes
-        elif use_kd and self._tp:
-            # the TP program consumes the fixed teacher as a TP-layout
-            # level-0 plane (its in-program forward is sharded too);
-            # convert once per teacher pytree identity
-            if (self._t_plane_cache is None
-                    or self._t_plane_cache[0] is not teacher):
-                self._t_plane_cache = (teacher, self.plane_of(0, teacher))
-            t_arg = self._t_plane_cache[1]
-        else:
-            t_arg = teacher
-        tail = (pack["shards"], pack["n"], pack["tables"], pack["counts"],
-                jnp.asarray(r0, jnp.int32), masks, w)
-        with self.obs.tracer.span("block_exec", cat="fl", level=level,
-                                  R=n_rounds, capacity=cap):
+        with tr.span("place_inputs", cat="fl", level=level):
+            if isinstance(weights, jax.Array) and weights.shape == (cap,):
+                w = weights               # pre-padded device array: no copy
+            else:
+                if weights is None:
+                    weights = [self.assignment.n_eff.get(pid, 1)
+                               for pid in members]
+                w = np.zeros(cap, np.float32)
+                w[:C] = np.asarray(weights, np.float32)
+                h2d += w.nbytes
+                w = self.place_member_sharded(jnp.asarray(w))
+            if (isinstance(step_masks, jax.Array)
+                    and step_masks.shape == (cap, S)):
+                masks = step_masks        # pre-padded device array: no copy
+            else:
+                masks = np.zeros((cap, S), np.float32)
+                masks[:C] = (np.ones((C, S), np.float32) if step_masks is None
+                             else np.asarray(step_masks, np.float32))
+                h2d += masks.nbytes
+                masks = self.place_member_sharded(jnp.asarray(masks))
+            prog = self._dispatch_programs(level, use_kd, cap, n_rounds,
+                                           balanced, banked, want_history,
+                                           t_per_round=t_per_round,
+                                           pack=pack, teacher_example=teacher)
+            if t_per_round:
+                t_arg = teacher_planes
+            elif use_kd and self._tp:
+                # the TP program consumes the fixed teacher as a TP-layout
+                # level-0 plane (its in-program forward is sharded too);
+                # convert once per teacher pytree identity
+                if (self._t_plane_cache is None
+                        or self._t_plane_cache[0] is not teacher):
+                    self._t_plane_cache = (teacher,
+                                           self.plane_of(0, teacher))
+                t_arg = self._t_plane_cache[1]
+            else:
+                t_arg = teacher
+            tail = (pack["shards"], pack["n"], pack["tables"],
+                    pack["counts"], jnp.asarray(r0, jnp.int32), masks, w)
+        with tr.span("block_exec", cat="fl", level=level, R=n_rounds,
+                     capacity=cap):
             if banked:
                 bank_plane, bank_w, bank_gain = bank
                 out = prog(plane, bank_plane, bank_w, *tail,
@@ -1075,24 +1081,24 @@ class FedRAC:
                 out = prog(plane, *tail, t_arg)
                 new_plane, bank_out = out[0], None
                 rest = out[1:]
-            self.obs.tracer.fence(new_plane)
-        losses = rest[0][:, :C]
-        history = rest[1] if want_history else None
-        if self.obs.on:
-            reg = self.obs.registry
-            reg.counter("fl/dispatch_blocks").inc()
-            reg.counter("fl/dispatch_rounds").inc(n_rounds)
-            if h2d:
-                reg.counter("fl/h2d_bytes").inc(h2d)
-            # per-round member losses are the block's host-bound output
-            reg.counter("fl/d2h_bytes").inc(
-                losses.size * losses.dtype.itemsize)
-            if self.mesh is not None:
-                # one psum over the data axis per fused round (see
-                # _dispatch_programs) — accounted analytically, since
-                # runtime collectives are invisible from inside jit; the
-                # HLO cross-check lives in launch/hlo_analysis
-                reg.counter("fl/psum_count").inc(n_rounds)
+            tr.fence(new_plane)
+        with tr.span("block_outputs", cat="fl", level=level):
+            losses = rest[0][:, :C]
+            history = rest[1] if want_history else None
+            if self.obs.on:
+                reg = self.obs.registry
+                reg.counter("fl/dispatch_blocks").inc()
+                if h2d:
+                    reg.counter("fl/h2d_bytes").inc(h2d)
+                # per-round member losses are the block's host-bound output
+                reg.counter("fl/d2h_bytes").inc(
+                    losses.size * losses.dtype.itemsize)
+                if self.mesh is not None:
+                    # one psum over the data axis per fused round (see
+                    # _dispatch_programs) — accounted analytically, since
+                    # runtime collectives are invisible from inside jit;
+                    # the HLO cross-check lives in launch/hlo_analysis
+                    reg.counter("fl/psum_count").inc(n_rounds)
         return DispatchOut(plane=new_plane, losses=losses, bank=bank_out,
                            history=history)
 

@@ -11,6 +11,13 @@ jitted call measures *submission*, not execution.  ``Tracer(fence=True)``
 makes ``tracer.fence(x)`` call ``jax.block_until_ready`` on ``x`` so span
 timings are honest on device, at the cost of serializing the pipeline —
 opt-in, off by default, and a no-op identity on the null tracer.
+
+**One clock with the device.**  While the JAX profiler is recording, a
+``Tracer`` span also enters a ``jax.profiler.TraceAnnotation`` of the same
+name, so every span lands on the profile's host ``python`` line, on the
+clock of the device planes.  Retroactive ``complete()`` events (compiles)
+and instants stay in this tracer only.  JAX is imported on the first span,
+so importing this module needs no backend.
 """
 from __future__ import annotations
 
@@ -18,8 +25,19 @@ import json
 import time
 
 
+_annotation = None       # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
@@ -28,6 +46,12 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        ann = _annotation or _trace_annotation()
+        # the annotation is made only while the profiler records: it opens
+        # before this span's clock reading and closes after its last one
+        self._ann = ann(self.name) if ann.is_enabled() else None
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -35,6 +59,8 @@ class _Span:
         t1 = time.perf_counter_ns()
         self._tracer._events.append(
             (self.name, self.cat, self._t0, t1 - self._t0, self.args))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 

@@ -333,7 +333,9 @@ class HeterogeneitySim:
             for r in range(r0, cfg.rounds):
                 with tr.span("round", cat="engine", round=r):
                     self._legacy_round(r, params, report, test)
-                self._round_boundary(r + 1, params, report, plane_mode=False)
+                with tr.span("round_boundary", cat="engine", round=r + 1):
+                    self._round_boundary(r + 1, params, report,
+                                         plane_mode=False)
             with tr.span("terminal_flush", cat="engine"):
                 self._terminal_flush(params, cfg.rounds, report)
             with tr.span("final_eval", cat="engine"):
@@ -402,7 +404,9 @@ class HeterogeneitySim:
                                 step_masks=masks, weights=weights,
                                 buffered=buffered, return_stack=want_stack)
                             tr.fence(out[0])
-                        params[lvl], losses = out[0], out[1]
+                        params[lvl] = out[0]
+                        with tr.span("loss_sync", cat="engine", level=lvl):
+                            losses = np.asarray(out[1])
                         if stats.banked:
                             stack = out[2]
                         for pid in stats.banked:
@@ -414,7 +418,7 @@ class HeterogeneitySim:
                     contributing = weights > 0
                     if contributing.any():
                         stats.mean_loss = float(
-                            np.mean(np.asarray(losses)[contributing]))
+                            np.mean(losses[contributing]))
                 if cfg.eval_every and (r + 1) % cfg.eval_every == 0:
                     stats.acc = fl.evaluate(lvl, params[lvl], test)
                 clusters.append(stats)
@@ -482,7 +486,8 @@ class HeterogeneitySim:
                 with tr.span("round_block", cat="engine", round=r):
                     r = self._dispatch_block(r, planes, report, test,
                                              buffered)
-                self._round_boundary(r, planes, report, plane_mode=True)
+                with tr.span("round_boundary", cat="engine", round=r):
+                    self._round_boundary(r, planes, report, plane_mode=True)
             with tr.span("terminal_flush", cat="engine"):
                 self._terminal_flush(planes, cfg.rounds, report,
                                      merge=self._anchored_merge_plane)
@@ -528,8 +533,10 @@ class HeterogeneitySim:
         # own dispatch DONATES planes[0] and the parallel-cadence teacher
         # stack still needs the block-start value afterwards (the
         # sequential cadence reads only post-round planes — no copy)
-        master_start = (jnp.copy(planes[0])
-                        if kd and cfg.schedule == "parallel" else None)
+        master_start = None
+        if kd and cfg.schedule == "parallel":
+            with tr.span("master_copy", cat="engine"):
+                master_start = jnp.copy(planes[0])
         master_hist = None                         # (L, D0) post-round
         rows = [[] for _ in range(L)]
         times = []
@@ -553,10 +560,12 @@ class HeterogeneitySim:
                                 planes[lvl], ripe, r, lvl)
                             tr.fence(planes[lvl])
                 if live or stats.banked:
-                    bank = (self._bank_carry(lvl, members,
-                                             ripe if live else [],
-                                             stats.banked, r)
-                            if buffered else None)
+                    bank = None
+                    if buffered:
+                        with tr.span("bank_carry", cat="engine", level=lvl):
+                            bank = self._bank_carry(lvl, members,
+                                                    ripe if live else [],
+                                                    stats.banked, r)
                     kw = {}
                     if lvl == 0:
                         # per-round master planes feed the slaves'
@@ -577,23 +586,27 @@ class HeterogeneitySim:
                     planes[lvl] = out.plane
                     if lvl == 0 and kw.get("want_history"):
                         master_hist = out.history
-                    losses = np.asarray(out.losses)
+                    with tr.span("loss_sync", cat="engine", level=lvl):
+                        losses = np.asarray(out.losses)
                     if stats.banked:
-                        bank_rows = out.bank[0]
-                        for pid in stats.banked:
-                            i = members.index(pid)
-                            self._bank[lvl].append({
-                                "pid": pid, "round": r + L - 1,
-                                "n_eff": fl.assignment.n_eff.get(pid, 1),
-                                "plane": bank_rows[i]})
-            contributing = weights > 0
-            for j in range(L):
-                s = self._clone_stats(stats)
-                s.flushed = (len(ripe) if j == 0
-                             else len(stats.banked) if live else 0)
-                if losses is not None and contributing.any():
-                    s.mean_loss = float(np.mean(losses[j][contributing]))
-                rows[j].append(s)
+                        with tr.span("rebank", cat="engine", level=lvl,
+                                     rows=len(stats.banked)):
+                            bank_rows = out.bank[0]
+                            for pid in stats.banked:
+                                i = members.index(pid)
+                                self._bank[lvl].append({
+                                    "pid": pid, "round": r + L - 1,
+                                    "n_eff": fl.assignment.n_eff.get(pid, 1),
+                                    "plane": bank_rows[i]})
+            with tr.span("round_stats", cat="engine", level=lvl):
+                contributing = weights > 0
+                for j in range(L):
+                    s = self._clone_stats(stats)
+                    s.flushed = (len(ripe) if j == 0
+                                 else len(stats.banked) if live else 0)
+                    if losses is not None and contributing.any():
+                        s.mean_loss = float(np.mean(losses[j][contributing]))
+                    rows[j].append(s)
             if (cfg.eval_every and (r + L) % cfg.eval_every == 0):
                 with tr.span("eval", cat="engine", level=lvl):
                     rows[L - 1][-1].acc = fl.evaluate(
@@ -656,7 +669,7 @@ class HeterogeneitySim:
         # membership may have shrunk below the banked backlog (event between
         # blocks): Σu-preserving compression fits it into the carry slots
         rows, us = aggregation.compress_bank_rows(
-            [b["plane"] for b in ripe], us, cap, obs=self.obs)
+            [b["plane"] for b in ripe], us, cap)
         bank_plane = jnp.zeros((cap, dp), jnp.float32)
         bank_w = np.zeros(cap, np.float32)
         if rows:
@@ -694,7 +707,7 @@ class HeterogeneitySim:
         wa, us = self._anchor_weights(entries, r, lvl)
         anchored = jax.tree.map(lambda x: wa * x, cur)
         return aggregation.merge_buffered(
-            anchored, [b["params"] for b in entries], us, obs=self.obs)
+            anchored, [b["params"] for b in entries], us)
 
     def _anchored_merge_plane(self, cur, entries: list, r: int, lvl: int):
         """Anchored flush over the flat parameter plane (dispatch engine).
@@ -758,7 +771,9 @@ class HeterogeneitySim:
                     self._async_commit(ev.level, t_done, report)
                     self._async_emit_rows(report)
                 self._merge_step += 1
-                self._async_boundary(report)
+                with tr.span("round_boundary", cat="engine",
+                             step=self._merge_step):
+                    self._async_boundary(report)
             if self._row_buf:
                 raise RuntimeError(
                     "async round assembly incomplete: rounds "
@@ -905,10 +920,13 @@ class HeterogeneitySim:
                             tr.fence(state)
                         new_state = state
                     if live or stats.banked:
-                        bank = (self._bank_carry(lvl, members,
-                                                 ripe if live else [],
-                                                 stats.banked, r)
-                                if buffered else None)
+                        bank = None
+                        if buffered:
+                            with tr.span("bank_carry", cat="engine",
+                                         level=lvl):
+                                bank = self._bank_carry(
+                                    lvl, members, ripe if live else [],
+                                    stats.banked, r)
                         kw = {}
                         if lvl == 0:
                             kw["want_history"] = kd and L > 1
@@ -930,15 +948,19 @@ class HeterogeneitySim:
                         new_state = out.plane
                         if lvl == 0 and kw.get("want_history"):
                             hist = out.history
-                        losses = np.asarray(out.losses)
+                        with tr.span("loss_sync", cat="engine", level=lvl):
+                            losses = np.asarray(out.losses)
                         if stats.banked:
-                            bank_rows = out.bank[0]
-                            for pid in stats.banked:
-                                i = members.index(pid)
-                                server.ledger.append({
-                                    "pid": pid, "round": r + L - 1,
-                                    "n_eff": fl.assignment.n_eff.get(pid, 1),
-                                    "plane": bank_rows[i]})
+                            with tr.span("rebank", cat="engine", level=lvl,
+                                         rows=len(stats.banked)):
+                                bank_rows = out.bank[0]
+                                for pid in stats.banked:
+                                    i = members.index(pid)
+                                    server.ledger.append({
+                                        "pid": pid, "round": r + L - 1,
+                                        "n_eff": fl.assignment.n_eff.get(
+                                            pid, 1),
+                                        "plane": bank_rows[i]})
                 else:
                     teacher = (self._async_teacher_legacy(r)
                                if kd and lvl > 0 else None)
@@ -962,7 +984,8 @@ class HeterogeneitySim:
                                 buffered=contribs, return_stack=buffered)
                             tr.fence(out[0])
                         new_state = out[0]
-                        losses = np.asarray(out[1])[None]
+                        with tr.span("loss_sync", cat="engine", level=lvl):
+                            losses = np.asarray(out[1])[None]
                         if stats.banked:
                             stack = out[2]
                             for pid in stats.banked:
@@ -978,15 +1001,16 @@ class HeterogeneitySim:
             rows = [ClusterRoundStats(level=lvl, time=0.0)
                     for _ in range(L)]
         else:
-            contributing = weights > 0
-            rows = []
-            for j in range(L):
-                s = self._clone_stats(stats)
-                s.flushed = (len(ripe) if j == 0
-                             else len(stats.banked) if live else 0)
-                if losses is not None and contributing.any():
-                    s.mean_loss = float(np.mean(losses[j][contributing]))
-                rows.append(s)
+            with tr.span("round_stats", cat="engine", level=lvl):
+                contributing = weights > 0
+                rows = []
+                for j in range(L):
+                    s = self._clone_stats(stats)
+                    s.flushed = (len(ripe) if j == 0
+                                 else len(stats.banked) if live else 0)
+                    if losses is not None and contributing.any():
+                        s.mean_loss = float(np.mean(losses[j][contributing]))
+                    rows.append(s)
             if cfg.eval_every and (r + L) % cfg.eval_every == 0:
                 state_now = new_state if new_state is not None else \
                     server.state

@@ -6,7 +6,8 @@ refuse (block layouts that do not match XLA's tiling, too much VMEM), which
 interpret mode never checks.  The shapes are the real ones: the plane
 lengths of the paper's full-width CNN (``--base-width 1.0`` on
 ``synth-cifar``, levels 0-2, plus level 0's column slice on a 2x2 mesh),
-flash attention at bf16 S1024 GQA and the KD loss at a 32k vocabulary.
+flash attention at bf16 S1024 GQA, the KD loss at a 32k vocabulary, and the
+full-width CNN's member step as the benchmark's FedAvg cluster runs it.
 
 The topology is described inside a fixture, never at import time: only one
 process at a time may load the TPU library, and every test worker imports
@@ -22,6 +23,8 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.core import aggregation
+from repro.core.client import make_cluster_update
+from repro.core.families import cnn_family
 from repro.kernels.distill import ops as distill_ops
 from repro.kernels.fedagg import ops as fedagg_ops
 from repro.kernels.flash import ops as flash_ops
@@ -116,3 +119,29 @@ def test_distill_compiles_past_one_row_block(one_chip, no_persistent_cache):
         lambda s, y, t: distill_ops.kd_loss(s, y, t, interpret=False),
         logits, labels, logits)
     assert "tpu_custom_call" in txt
+
+
+def test_cnn_member_step_compiles_without_select_and_scatter(
+        one_chip, no_persistent_cache):
+    """The full-width CNN's member step at 28x28x1: 64 members (the
+    cluster's capacity) x 4 SGD steps x batch 16.  Its pooled layers use
+    the fused ReLU-pool, so the chip's program holds no select-and-scatter
+    (XLA's max-pool backward) and no reduce-window."""
+    members, steps, batch = 64, 4, 16
+    fam = cnn_family(classes=10, in_channels=1, alpha=0.5, base_width=1.0,
+                     input_hw=28)
+    p = jax.eval_shape(lambda: fam.init(jax.random.PRNGKey(0), 0))
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda l: spec((members,) + l.shape, l.dtype), p)
+    batches = {"x": spec((members, steps, batch, 28, 28, 1)),
+               "y": spec((members, steps, batch), jnp.int32)}
+    step = make_cluster_update(lambda prm, b: fam.loss_and_logits(0, prm, b),
+                               0.05)
+    txt = step.lower(params, batches, spec((members, steps))).compile(
+    ).as_text()
+    assert "convolution" in txt
+    assert "select-and-scatter" not in txt
+    assert "reduce-window" not in txt
